@@ -17,6 +17,7 @@ import time
 import traceback
 
 from benchmarks import common
+from repro.compile_cache import configure_compile_cache
 
 SUITES = [
     ("table2", "benchmarks.bench_table2_bf_vs_rl"),
@@ -41,6 +42,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated suite names")
     args = ap.parse_args()
+    configure_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")

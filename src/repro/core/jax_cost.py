@@ -24,7 +24,7 @@ Design constraints that shape the port:
   branch-free.
 * **Precision.** All arrays are built from float64 NumPy inputs and take
   whatever precision JAX canonicalizes to: float64 under
-  ``jax.experimental.enable_x64()`` (the fused scheduler runs its cost
+  ``jax.enable_x64(True)`` (the fused scheduler runs its cost
   side there — agreement with the oracle is then ~1e-9 relative), float32
   otherwise (agreement to ~1e-3 on log-cost; documented in DESIGN.md).
 """
@@ -264,7 +264,10 @@ def provision(
         hess = (cp - 2 * cc + cm) / (h * h)
         step = jnp.where(
             (hess <= 0.0) | ~jnp.isfinite(hess),
-            -jnp.copysign(0.1 * tau, g),
+            # copysign(0.1·τ, g) for every g an active lane can hold (a
+            # difference of positive costs is never -0.0); the TPU's x64
+            # rewrite has no f64 sign-bit bitcast, which copysign needs
+            jnp.where(g < 0.0, 0.1 * tau, -0.1 * tau),
             -g / hess,
         )
         new_tau = jnp.where(active, jnp.maximum(tau_min, tau + step), tau)

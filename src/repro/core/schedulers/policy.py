@@ -137,7 +137,7 @@ def _step_mask(feats, mask):
     """(L,) float validity weights for padded layer rows (1.0 = real).
 
     Explicit ``feats.dtype`` keeps policy math in float32 even when the
-    caller traces under ``jax.experimental.enable_x64()`` (the fused
+    caller traces under ``jax.enable_x64(True)`` (the fused
     search runs its cost side in f64 but the policy side must stay f32 to
     match the unfused per-round path).
     """

@@ -21,7 +21,7 @@ Two implementations of the search loop:
   (``seed_from_device``) and checks early stopping *between* chunks.
   ``schedule_many`` additionally ``vmap``s the whole search across several
   models (layer features padded to a common length, see DESIGN.md).
-  Runs its cost side under ``jax.experimental.enable_x64()`` so rewards
+  Runs its cost side under ``jax.enable_x64(True)`` so rewards
   agree with the NumPy oracle to ~1e-9 while policy math stays float32.
 * **unfused** (``fused=False``): the original per-round Python loop — one
   device round-trip per round, NumPy ``batched_soft_plan_cost`` scoring.
@@ -335,7 +335,7 @@ class RLScheduler(Scheduler):
         greedy_params = [None] * M  # per-model params at its final round
         chunk_times: list[float] = []
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             feats = jnp.asarray(feats_np)   # float32 (explicit in builder)
             mask = jnp.asarray(mask_np)
             cts = [jax_cost.cost_tensors(p, f, j, pad_to=Lmax)

@@ -13,14 +13,19 @@ Here the routing *metadata* (which token fills which expert slot) is
 computed once with cheap integer ops (:func:`slot_maps`), and the heavy
 D-dimensional row movement happens in two Pallas kernels:
 
-* **dispatch** — grid ``(G, E, C)``: each step DMAs one source token row
-  HBM→VMEM (row id scalar-prefetched from the slot map, like
-  ``embedding_bag``) and writes it, scaled by the slot weight, into its
-  slab slot.  The repeated ``(G, N·K, D)`` source and the scatter pass
-  never exist in HBM.
-* **combine** — grid ``(G, S, K)`` with K sequential: a per-token f32
-  VMEM accumulator sums the K gate-weighted expert rows; the
+* **dispatch** — grid ``(G, E, C / tile)``: each step DMAs its slots'
+  source token rows HBM→VMEM (row ids scalar-prefetched from the slot
+  map) and writes them, scaled by the slot weights, into the slab.  The
+  repeated ``(G, N·K, D)`` source and the scatter pass never exist in
+  HBM.
+* **combine** — grid ``(G, S / TOKENS)``: each step DMAs the K expert
+  rows of each of its tokens and sums them gate-weighted in f32; the
   ``(G, N·K, D)`` gathered intermediate never materializes.
+
+Both keep the gathered operand in HBM (``pl.ANY``) and move rows with
+explicit DMAs over a ``(rows, D / 128, 128)`` view (:func:`_row_view`):
+the TPU refuses a one-row ``(1, D)`` block or DMA slice of the tiled
+``(rows, D)`` layout, whose second-minor dimension comes in tiles of 8.
 
 Gradients: both ops are linear in their float inputs and each one's
 transpose is the other, so ``custom_vjp`` implements dispatch's backward
@@ -45,8 +50,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 
 # --------------------------------------------------------------------------
@@ -105,13 +108,48 @@ def slot_weights(slot_nk, wtok):
 # --------------------------------------------------------------------------
 
 
-def _dispatch_kernel(src_ref, w_ref, x_ref, out_ref):
-    g = pl.program_id(0)
-    e = pl.program_id(1)
-    c = pl.program_id(2)
-    w = w_ref[g, e, c].astype(jnp.float32)
-    row = x_ref[...].astype(jnp.float32) * w
-    out_ref[...] = row.reshape(out_ref.shape).astype(out_ref.dtype)
+#: tokens per combine grid step
+TOKENS = 8
+
+
+def _row_tile(n: int, cap: int = 32) -> int:
+    """Largest divisor of ``n`` that is at most ``cap``: slab slots per
+    dispatch grid step."""
+    return max(t for t in range(1, min(n, cap) + 1) if n % t == 0)
+
+
+def _row_view(a):
+    """``(..., D)`` → ``(..., D / L, L)`` with ``L = 128`` when it divides
+    D (else ``L = D``).  The row index becomes an untiled leading
+    dimension, so a single row is a whole-tile slice the TPU's DMA
+    accepts; a ``(1, D)`` slice of the tiled ``(rows, D)`` layout is
+    refused.  XLA relayouts the array once on the way in."""
+    D = a.shape[-1]
+    L = 128 if D % 128 == 0 else D
+    return a.reshape(a.shape[:-1] + (D // L, L))
+
+
+def _dispatch_kernel(src_ref, x_hbm, w_ref, out_ref, rows, sem, *,
+                     num_experts: int, capacity: int, tile: int):
+    g, e, ci = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    base = (g * num_experts + e) * capacity + ci * tile
+
+    def copy(j):
+        src = jnp.maximum(src_ref[base + j], 0)
+        return pltpu.make_async_copy(x_hbm.at[g, src], rows.at[j], sem.at[0])
+
+    def start(j, c):
+        copy(j).start()
+        return c
+
+    def wait(j, c):
+        copy(j).wait()
+        return c
+
+    jax.lax.fori_loop(0, tile, start, 0)
+    jax.lax.fori_loop(0, tile, wait, 0)
+    out_ref[0, 0] = (rows[...].astype(jnp.float32) * w_ref[0, 0]
+                     ).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("num_experts", "capacity",
@@ -120,83 +158,116 @@ def dispatch_pallas(x, slot_src, slot_w, *, num_experts: int, capacity: int,
                     interpret: bool = False):
     """x: (G, S, D); slot_src/slot_w: (G, E, C) → slabs (G, E, C, D).
 
-    One grid step per slot: the source row is scalar-prefetched (SMEM) so
-    each step DMAs exactly one ``(1, D)`` row HBM→VMEM — the K-repeated
-    token buffer of the reference path never materializes.
+    Grid ``(G, E, C / tile)``: the flat source-row map is scalar-prefetched
+    (SMEM) and ``x`` stays in HBM, so each step DMAs exactly its ``tile``
+    source rows HBM→VMEM, scales them by their slot weights and writes
+    them into the slab — the K-repeated token buffer of the reference
+    path never materializes.
     """
     G, S, D = x.shape
     E, C = num_experts, capacity
+    tile = _row_tile(C)
+    xr = _row_view(x)                                    # (G, S, D/L, L)
+    R, L = xr.shape[2:]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # slot_src (int32), slot_w (f32)
-        grid=(G, E, C),
+        num_scalar_prefetch=1,  # flat slot_src (int32)
+        grid=(G, E, C // tile),
         in_specs=[
-            pl.BlockSpec((1, 1, D),
-                         lambda g, e, c, src, w: (g, jnp.maximum(src[g, e, c], 0), 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, 1, tile, 1, 1),
+                         lambda g, e, c, src: (g, e, c, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, D),
-                               lambda g, e, c, src, w: (g, e, c, 0)),
+        out_specs=pl.BlockSpec((1, 1, tile, R, L),
+                               lambda g, e, c, src: (g, e, c, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((tile, R, L), x.dtype),
+                        pltpu.SemaphoreType.DMA((1,))],
     )
-    return pl.pallas_call(
-        _dispatch_kernel,
+    out = pl.pallas_call(
+        functools.partial(_dispatch_kernel, num_experts=E, capacity=C,
+                          tile=tile),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, E, C, D), x.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        out_shape=jax.ShapeDtypeStruct((G, E, C, R, L), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
-    )(slot_src, slot_w.astype(jnp.float32), x)
+    )(slot_src.reshape(-1), xr,
+      slot_w.astype(jnp.float32).reshape(G, E, C, 1, 1))
+    return out.reshape(G, E, C, D)
 
 
-def _combine_kernel(eid_ref, pos_ref, w_ref, buf_ref, out_ref, acc_ref, *,
+def _combine_kernel(eid_ref, pos_ref, buf_hbm, w_ref, out_ref, rows, sem, *,
                     top_k: int):
-    g = pl.program_id(0)
-    s = pl.program_id(1)
-    k = pl.program_id(2)
+    g, t0 = pl.program_id(0), pl.program_id(1)
+    base = (g * pl.num_programs(1) + t0) * TOKENS * top_k
 
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def copy(t, k):
+        i = base + t * top_k + k
+        return pltpu.make_async_copy(buf_hbm.at[g, eid_ref[i], pos_ref[i]],
+                                     rows.at[k, t], sem.at[0])
 
-    w = w_ref[g, s, k].astype(jnp.float32)
-    acc_ref[...] += buf_ref[...].reshape(acc_ref.shape).astype(jnp.float32) * w
+    def start(t, c):
+        for k in range(top_k):
+            copy(t, k).start()
+        return c
 
-    @pl.when(k == top_k - 1)
-    def _finalize():
-        out_ref[...] = acc_ref[...].reshape(out_ref.shape).astype(out_ref.dtype)
+    def wait(t, c):
+        for k in range(top_k):
+            copy(t, k).wait()
+        return c
+
+    jax.lax.fori_loop(0, TOKENS, start, 0)
+    jax.lax.fori_loop(0, TOKENS, wait, 0)
+    acc = rows[0].astype(jnp.float32) * w_ref[0, 0]
+    for k in range(1, top_k):
+        acc += rows[k].astype(jnp.float32) * w_ref[0, k]
+    out_ref[0] = acc.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def combine_pallas(buf, eid, pos, w, *, interpret: bool = False):
     """buf: (G, E, C, D); eid/pos/w: (G, S, K) → tokens (G, S, D).
 
-    Grid (G, S, K) with K sequential: the expert row for (token, k) is
-    block-selected via the scalar-prefetched (eid, pos) pair and summed
-    gate-weighted into a f32 VMEM accumulator — the (G, S, K, D) gather
-    intermediate never exists.
+    Grid ``(G, S / TOKENS)``: ``buf`` stays in HBM and the (eid, pos)
+    routing is scalar-prefetched, so each step DMAs the ``TOKENS · K``
+    expert rows its tokens need HBM→VMEM and sums them gate-weighted in
+    f32 — the (G, S, K, D) gather intermediate never exists.  S is padded
+    to whole token tiles with zero-weight rows.
     """
     G, E, C, D = buf.shape
     _, S, K = eid.shape
+    pad = (-S) % TOKENS
+    Sp = S + pad
+    eid_p, pos_p, w_p = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+                         for a in (eid, pos, w))
+    w_t = jnp.moveaxis(w_p.astype(jnp.float32), 2, 1)[..., None, None]
+    br = _row_view(buf)                                  # (G, E, C, D/L, L)
+    R, L = br.shape[3:]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # eid, pos (int32), w (f32)
-        grid=(G, S, K),
+        num_scalar_prefetch=2,  # flat eid, pos (int32)
+        grid=(G, Sp // TOKENS),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, D),
-                         lambda g, s, k, e, p, w: (g, e[g, s, k], p[g, s, k], 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, K, TOKENS, 1, 1),
+                         lambda g, t, e, p: (g, 0, t, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda g, s, k, e, p, w: (g, s, 0)),
-        scratch_shapes=[pltpu.VMEM((1, D), jnp.float32)],
+        out_specs=pl.BlockSpec((1, TOKENS, R, L),
+                               lambda g, t, e, p: (g, t, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((K, TOKENS, R, L), buf.dtype),
+                        pltpu.SemaphoreType.DMA((1,))],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_combine_kernel, top_k=K),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, S, D), buf.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        out_shape=jax.ShapeDtypeStruct((G, Sp, R, L), buf.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
-    )(eid, pos, w.astype(jnp.float32), buf)
+    )(eid_p.reshape(-1), pos_p.reshape(-1), br, w_t)
+    return out.reshape(G, Sp, D)[:, :S]
 
 
 # --------------------------------------------------------------------------

@@ -18,12 +18,12 @@ This module stores KV state in a **shared page pool** instead:
   Logical position ``t`` of sequence ``b`` lives at
   ``k_pages[page_table[b, t // page_size], t % page_size]``.
 
-The decode kernel runs on a ``(B, KV, pages)`` grid with the page axis
+The decode kernel runs on a ``(B, pages)`` grid with the page axis
 sequential, online-softmax accumulators in VMEM (same algorithm as
 ``flash_attention``), and the page table + per-sequence positions
 scalar-prefetched (SMEM) so each grid step DMAs exactly one *used* page
-HBM→VMEM.  Steps past the sequence's last used page — and, for
-sliding-window layers, pages wholly before the window — clamp their
+(all its KV heads) HBM→VMEM.  Steps past the sequence's last used page
+— and, for sliding-window layers, pages wholly before the window — clamp their
 block index to the previous step's, which the Pallas pipeline recognizes
 as "same block" and skips the DMA: a 12-token sequence in a 4096-token
 pool moves one page of KV, not 4096 rows.
@@ -44,8 +44,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -190,9 +188,10 @@ class PagePool:
         need = self.pages_for(tokens)
         if need > self.pages_per_seq:
             raise ValueError(f"{tokens} tokens exceed pages_per_seq capacity")
+        if need - len(self._owned[slot]) > self.available_pages:
+            # all-or-nothing: a failed grow keeps the slot's prior pages
+            raise MemoryError("pool exhausted")
         while len(self._owned[slot]) < need:
-            if not self._free or self.available_pages <= 0:
-                raise MemoryError("pool exhausted")
             pid = self._free.pop()
             self.table[slot, len(self._owned[slot])] = pid
             self._owned[slot].append(pid)
@@ -221,11 +220,11 @@ def _page_window(q_pos, page_size: int, window):
     return first, last
 
 
-def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale, page_size, num_pages_seq,
-                   window, softcap_val):
+def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, head_ref, row_ref,
+                   o_ref, m_scr, l_scr, acc_scr, *, scale, page_size,
+                   num_pages_seq, window, softcap_val):
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
@@ -238,17 +237,18 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when((p >= first) & (p <= last))
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)                 # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (ps, hd)
+        q = q_ref[0].astype(jnp.float32)                    # (H, hd)
+        k = k_ref[0].astype(jnp.float32)                    # (ps·KV, hd)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale                                           # (G, ps)
+        ) * scale                                           # (H, ps·KV)
         if softcap_val is not None:
             s = softcap_val * jnp.tanh(s / softcap_val)
-        kpos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        ok = kpos <= q_pos
+        # page row r holds token r // KV of KV head r % KV; a query head
+        # attends only the rows of its own KV head
+        kpos = p * page_size + row_ref[...]                 # (1, ps·KV)
+        ok = (head_ref[...] != 0) & (kpos <= q_pos)
         if window is not None:
             ok &= kpos > q_pos - window
         s = jnp.where(ok, s, NEG_INF)
@@ -259,7 +259,7 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         pexp = jnp.exp(s - m_new)
         l_scr[...] = l_prev * alpha + pexp.sum(-1, keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            pexp, v_ref[0, :, 0, :].astype(jnp.float32),
+            pexp, v_ref[0].astype(jnp.float32),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -267,7 +267,7 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(p == num_pages_seq - 1)
     def _finalize():
-        o_ref[0, 0] = (
+        o_ref[0] = (
             acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
         ).astype(o_ref.dtype)
 
@@ -282,49 +282,70 @@ def paged_decode_pallas(q, k_pages, v_pages, page_table, q_pos, *,
     page_table: (B, P) int32; q_pos: (B,) int32 — the new token's
     position (== tokens already cached).  Returns (B, KV, G, hd).
 
-    Grid (B, KV, P) with the page axis sequential.  The index map clamps
-    the physical page into the live ``[first, last]`` page span, so
-    out-of-span grid steps repeat the previous block index and the
-    pipeline skips their DMA — only *used* pages move HBM→VMEM.
+    Grid (B, P) with the page axis sequential.  Each step DMAs one whole
+    page, every KV head of it, viewed as ``(ps·KV, hd)`` rows: the block's
+    last two dimensions are the array's, which the TPU lowering requires
+    (a one-head ``(ps, 1, hd)`` block is refused).  All ``H = KV·G``
+    query heads score the page in one matmul and a head mask keeps each
+    query head on its own KV head's rows — KV× the scores of a per-head
+    grid, which a bandwidth-bound decode step does not feel.  The index
+    map clamps the physical page into the live ``[first, last]`` span, so
+    out-of-span steps repeat the previous block index and the pipeline
+    skips their DMA — only *used* pages move HBM→VMEM.
     """
     B, KV, G, hd = q.shape
     N, ps, _, _ = k_pages.shape
     P = page_table.shape[1]
+    H, R = KV * G, ps * KV
     scale = 1.0 / float(np.sqrt(hd))
+    # row-major reshapes; on the TPU a pool view is a bitcast when KV
+    # fills a sublane tile (KV=8 in f32), else XLA relayouts the pool
+    qf = q.reshape(B, H, hd)
+    kf = k_pages.reshape(N, R, hd)
+    vf = v_pages.reshape(N, R, hd)
+    rows = np.arange(R)
+    same_head = jnp.asarray(
+        (np.arange(H)[:, None] // G) == (rows[None, :] % KV), jnp.int32)
+    row_pos = jnp.asarray((rows // KV)[None, :], jnp.int32)
 
-    def page_map(b, kv, p, pt, pos):
+    def page_map(b, p, pt, pos):
         first, last = _page_window(pos[b], ps, window)
         pe = jnp.clip(p, first, last)
-        return (jnp.maximum(pt[b, pe], 0), 0, kv, 0)
+        return (jnp.maximum(pt[b, pe], 0), 0, 0)
+
+    def fixed(b, p, pt, pos):
+        return (0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # page_table, q_pos (SMEM)
-        grid=(B, KV, P),
+        grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, kv, p, pt, pos: (b, kv, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd), page_map),
-            pl.BlockSpec((1, ps, 1, hd), page_map),
+            pl.BlockSpec((1, H, hd), lambda b, p, pt, pos: (b, 0, 0)),
+            pl.BlockSpec((1, R, hd), page_map),
+            pl.BlockSpec((1, R, hd), page_map),
+            pl.BlockSpec((H, R), fixed),
+            pl.BlockSpec((1, R), fixed),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, kv, p, pt, pos: (b, kv, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, hd), lambda b, p, pt, pos: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
             _decode_kernel, scale=scale, page_size=ps, num_pages_seq=P,
             window=window, softcap_val=softcap,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(page_table, q_pos, q, k_pages, v_pages)
+    )(page_table, q_pos, qf, kf, vf, same_head, row_pos)
+    return out.reshape(B, KV, G, hd)
 
 
 # --------------------------------------------------------------------------

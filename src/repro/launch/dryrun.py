@@ -66,8 +66,6 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             ),
         }
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: one dict per program
-            ca = ca[0] if ca else {}
         rec["cost"] = {
             "flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

@@ -6,16 +6,6 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """`jax.make_mesh` with Auto axis types where the jax version has them
-    (jax.sharding.AxisType arrived after 0.4.x; older versions are
-    Auto-only and take no ``axis_types`` argument)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; ``multi_pod`` adds the 2-pod axis.
 
@@ -24,9 +14,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_smoke_mesh():
     """1×1 mesh over the single CPU device (smoke tests / examples)."""
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

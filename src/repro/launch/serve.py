@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.compile_cache import configure_compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.core.admission import (AdmissionPolicy, COMPLETED, OUTCOMES,
                                   PREEMPTED, REJECTED, TIMED_OUT)
@@ -688,6 +689,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="llama3.2-1b")
     ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
@@ -737,6 +739,7 @@ def main() -> None:
                     help="decode-chunk stall threshold in seconds "
                          "(stall => obs instant + queue shed pass)")
     args = ap.parse_args()
+    configure_compile_cache()
     if args.obs_dir:
         obs.configure(run_dir=args.obs_dir)
     controller = None

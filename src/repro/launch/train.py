@@ -28,6 +28,7 @@ import numpy as np
 
 from repro import obs
 from repro.checkpoint import save_checkpoint
+from repro.compile_cache import configure_compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.data import PrefetchLoader, SyntheticTokenDataset
 from repro.launch.steps import init_train_state, make_train_step
@@ -237,6 +238,7 @@ def main() -> None:
                          "workers inherit the switch and ship their spans "
                          "back as separate pid lanes)")
     args = ap.parse_args()
+    configure_compile_cache()
     if args.obs_dir:
         # before any transport spawn, so shard workers inherit REPRO_OBS
         obs.configure(run_dir=args.obs_dir)

@@ -24,17 +24,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# top-level jax.shard_map arrived after 0.4.x
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def make_stage_mesh(num_stages: int):
-    from repro.launch.mesh import make_mesh_compat
-
-    return make_mesh_compat((num_stages,), ("stage",))
+    return jax.make_mesh((num_stages,), ("stage",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def pipeline_apply(
@@ -77,7 +70,7 @@ def pipeline_apply(
         stacked = jnp.stack(outs[S - 1 :], axis=0)  # (M, mb, d)
         return stacked[None]  # (1, M, mb, d) per-stage block
 
-    out = _shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P(None)),

@@ -488,13 +488,11 @@ class ShardedTable:
 
 
 def _to_memory_kind(arr, kind: str):
-    """device_put with a memory kind on runtimes that support it (TPU);
-    identity elsewhere — the CPU container simulates tiers in software."""
+    """device_put with a memory kind on a TPU; identity elsewhere — the
+    CPU simulates tiers in software.  On a TPU a placement the runtime
+    refuses raises: the hot cache must really land in HBM."""
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         return arr
-    try:
-        sharding = jax.sharding.SingleDeviceSharding(dev, memory_kind=kind)
-        return jax.device_put(arr, sharding)
-    except (ValueError, TypeError, NotImplementedError):
-        return arr
+    sharding = jax.sharding.SingleDeviceSharding(dev, memory_kind=kind)
+    return jax.device_put(arr, sharding)

@@ -410,4 +410,7 @@ def train_ctr_elastic(cfg: CTRConfig | None = None, *, steps: int = 200,
         "transport_counters": transport_counters,
         "pull_gb": tel["pull"]["bytes"] / 1e9,
         "push_gb": tel["push"]["bytes"] / 1e9,
+        # where the dense tower trained: ["tpu"] on the chip
+        "tower_platforms": sorted({d.platform for leaf in jax.tree.leaves(tower)
+                                   for d in leaf.devices()}),
     }

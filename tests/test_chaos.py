@@ -36,10 +36,7 @@ import time
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:   # in-repo deterministic fallback
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import read_pointer
 from repro.ps.elastic import ElasticPSFleet, PSUnrecoverable

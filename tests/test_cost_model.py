@@ -3,10 +3,7 @@
 import math
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # no hard dep: deterministic fallback shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 
 from repro.core import (

@@ -4,7 +4,7 @@
 with ``batched_soft_plan_cost`` on soft cost, true cost, and feasibility
 over randomized plans/fleets/jobs.  Documented tolerance (see
 ``jax_cost`` module docstring): ~1e-9 relative under
-``jax.experimental.enable_x64()`` (the mode the fused scheduler actually
+``jax.enable_x64(True)`` (the mode the fused scheduler actually
 runs in), ~1e-1 on log10-cost in float32 (Newton/ceil rounding can flip
 an integer replica count near a boundary).
 
@@ -21,10 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     INFEASIBLE,
@@ -51,7 +48,7 @@ def _random_plans(rng, n, L, T):
 
 def _check_x64_equivalence(profiles, fleet, job, A, rel=1e-9):
     bc, soft_np = batched_soft_plan_cost(A, profiles, fleet, job)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         soft_j, cost_j, feas_j = jax_cost.jnp_soft_plan_cost(
             A, profiles, fleet, job
         )
@@ -132,7 +129,7 @@ class TestLayerPadding:
         profiles = paper_model_profiles("NCE", fleet)
         rng = np.random.default_rng(5)
         A = _random_plans(rng, 24, 5, 2)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             soft_u, cost_u, feas_u = jax_cost.jnp_soft_plan_cost(
                 A, profiles, fleet, JOB
             )

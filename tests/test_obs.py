@@ -25,10 +25,7 @@ from collections import defaultdict
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # no hard dep: deterministic fallback shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro import obs
 from repro.core.profiles import B_O

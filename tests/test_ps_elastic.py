@@ -24,10 +24,7 @@ import signal
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:   # in-repo deterministic fallback
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.ps.elastic import BucketSpec, ElasticPSFleet
 from repro.ps.transport import PSShardLost

@@ -18,10 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # no hard dep: deterministic fallback shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.kernels.paged_attention import PagePool
