@@ -1,0 +1,15 @@
+"""Share (%) of the MoE combine kernel's roofline: the least time its
+calls in the traced window could take (bytes from each call's shapes
+over the HBM peak; its arithmetic is far below the compute bound) over
+their device time.  Nothing to read where the trace has no combine
+kernel."""
+
+from benchmarks.chip.flops import roofline_share
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    share = roofline_share("combine", run.trace.kernel_ops("combine_pallas"),
+                           run.peaks)
+    return None if share is None else share[0]
