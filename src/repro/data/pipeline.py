@@ -6,7 +6,8 @@ into CPU-worker memory, and spills to SSD when RAM is tight.  Here:
 * :class:`SyntheticTokenDataset` — deterministic synthetic LM batches
   (seeded per-step PRNG; reproducible across restarts and host counts);
 * :class:`PrefetchLoader` — background-thread prefetch with a bounded
-  queue (the paper's prefetch-and-cache behaviour);
+  queue (the paper's prefetch-and-cache behaviour); its waits and the
+  worker's batch making are ``data.*`` spans (:mod:`repro.obs.scopes`);
 * :func:`shard_batch` — places a host batch onto the mesh with the batch
   axis sharded over ``("pod", "data")``.
 """
@@ -20,6 +21,9 @@ from typing import Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs import scopes
+from repro.obs.trace import span
 
 
 class SyntheticTokenDataset:
@@ -100,15 +104,21 @@ class PrefetchLoader:
 
         def worker():
             try:
-                for b in dataset:
+                batches = iter(dataset)
+                while True:
+                    with span(scopes.DATA_PRODUCE):
+                        b = next(batches, _SENTINEL)
+                    if b is _SENTINEL:
+                        return
                     placed = False
-                    while not self._stop.is_set():
-                        try:
-                            self._q.put(b, timeout=self._put_timeout)
-                            placed = True
-                            break
-                        except queue.Full:
-                            continue
+                    with span(scopes.DATA_PUT_WAIT):
+                        while not self._stop.is_set():
+                            try:
+                                self._q.put(b, timeout=self._put_timeout)
+                                placed = True
+                                break
+                            except queue.Full:
+                                continue
                     if not placed:
                         return  # close() requested while queue stayed full
             finally:
@@ -140,7 +150,8 @@ class PrefetchLoader:
     def __next__(self):
         if self._done:
             raise StopIteration
-        item = self._q.get()
+        with span(scopes.DATA_WAIT):
+            item = self._q.get()
         if item is _SENTINEL:
             self._done = True
             raise StopIteration

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from repro.models import decoder as dec
 from repro.models.config import ArchConfig
+from repro.obs import scopes
 from repro.optim import adamw_init, adamw_update, clip_by_global_norm
 
 
@@ -45,15 +46,18 @@ def make_train_step(cfg: ArchConfig, *, lr: float = 3e-4,
             def micro(carry, mb):
                 gacc, lacc = carry
                 loss, g = grads_of(params, mb)
-                gacc = jax.tree.map(jnp.add, gacc, g)
-                return (gacc, lacc + loss), None
+                with jax.named_scope(scopes.GRAD_ACCUM):
+                    gacc = jax.tree.map(jnp.add, gacc, g)
+                    return (gacc, lacc + loss), None
 
-            zeros = jax.tree.map(jnp.zeros_like, params)
+            with jax.named_scope(scopes.GRAD_ACCUM):
+                zeros = jax.tree.map(jnp.zeros_like, params)
             (grads, loss), _ = jax.lax.scan(
                 micro, (zeros, jnp.zeros((), jnp.float32)), split
             )
-            grads = jax.tree.map(lambda g: g / n_micro, grads)
-            loss = loss / n_micro
+            with jax.named_scope(scopes.GRAD_ACCUM):
+                grads = jax.tree.map(lambda g: g / n_micro, grads)
+                loss = loss / n_micro
         else:
             loss, grads = grads_of(params, batch)
         grads, gnorm = clip_by_global_norm(grads, clip)

@@ -56,9 +56,7 @@ def train(arch: str, *, reduced: bool = True, steps: int = 50,
     reg = obs.REGISTRY
     t0 = time.perf_counter()
     for i in range(steps):
-        td = time.perf_counter()
         batch_np = next(loader)
-        reg.histogram("train.data_s").record(time.perf_counter() - td)
         ts = time.perf_counter()
         with obs_trace.span("train.step", "train", step=i):
             jbatch = {k: jnp.asarray(v) for k, v in batch_np.items()}

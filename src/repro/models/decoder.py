@@ -31,6 +31,7 @@ from repro.nn import mamba as mamba_mod
 from repro.nn import moe as moe_mod
 from repro.nn import rwkv as rwkv_mod
 from repro.nn.attention import AttnSpec
+from repro.obs import scopes
 from repro.nn.base import (
     cross_entropy_loss,
     layernorm,
@@ -143,10 +144,12 @@ def init_model(cfg: ArchConfig, key, *, dtype=jnp.float32):
 def _cast(p, dtype):
     """Cast float params to the compute dtype (norms etc. recompute in f32
     internally); non-float leaves pass through."""
-    return jax.tree.map(
-        lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a,
-        p,
-    )
+    with jax.named_scope(scopes.CAST):
+        return jax.tree.map(
+            lambda a: (a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating)
+                       else a),
+            p,
+        )
 
 
 def _ffn_block(cfg, spec: LayerSpec, p, x, *, mode: str = "seq", cache=None):
@@ -158,27 +161,28 @@ def _ffn_block(cfg, spec: LayerSpec, p, x, *, mode: str = "seq", cache=None):
     aux = jnp.zeros((), jnp.float32)
     if spec.ffn == "none":
         return x, aux, {}
-    upd: dict[str, Any] = {}
-    h = _norm(cfg, p["norm2"], x)
-    if spec.ffn == "dense":
-        y = moe_mod.dense_ffn(p["ffn"], h)
-    elif spec.ffn == "moe":
-        y, moe_aux = moe_mod.moe_ffn(p["ffn"], h, top_k=cfg.moe_top_k,
-                                     capacity_factor=cfg.moe_capacity_factor,
-                                     impl=cfg.moe_impl)
-        aux = aux + moe_aux["aux_loss"]
-    elif spec.ffn == "channel_mix":
-        if mode == "decode":
-            y, upd = rwkv_mod.decode_channel_mix(p["ffn"], h, cache)
+    with jax.named_scope(scopes.FFN):
+        upd: dict[str, Any] = {}
+        h = _norm(cfg, p["norm2"], x)
+        if spec.ffn == "dense":
+            y = moe_mod.dense_ffn(p["ffn"], h)
+        elif spec.ffn == "moe":
+            y, moe_aux = moe_mod.moe_ffn(
+                p["ffn"], h, top_k=cfg.moe_top_k,
+                capacity_factor=cfg.moe_capacity_factor, impl=cfg.moe_impl)
+            aux = aux + moe_aux["aux_loss"]
+        elif spec.ffn == "channel_mix":
+            if mode == "decode":
+                y, upd = rwkv_mod.decode_channel_mix(p["ffn"], h, cache)
+            else:
+                y = rwkv_mod.channel_mix_seq(p["ffn"], h)
+                if mode == "prefill":
+                    upd = {"cm_shift": h[:, -1].astype(jnp.float32)}
         else:
-            y = rwkv_mod.channel_mix_seq(p["ffn"], h)
-            if mode == "prefill":
-                upd = {"cm_shift": h[:, -1].astype(jnp.float32)}
-    else:
-        raise ValueError(spec.ffn)
-    if spec.post_norm:
-        y = _norm(cfg, p["norm_post2"], y)
-    return x + y, aux, upd
+            raise ValueError(spec.ffn)
+        if spec.post_norm:
+            y = _norm(cfg, p["norm_post2"], y)
+        return x + y, aux, upd
 
 
 def _apply_layer(cfg, spec: LayerSpec, p, x, *, positions, cross_kv=None,
@@ -186,41 +190,42 @@ def _apply_layer(cfg, spec: LayerSpec, p, x, *, positions, cross_kv=None,
     """One layer forward. Returns (x, moe_aux)."""
     p = act.gather_params(_cast(p, x.dtype), cfg)
     aux = jnp.zeros((), jnp.float32)
-    h = _norm(cfg, p["norm1"], x)
-    aspec = _attn_spec(cfg, spec, causal=causal)
-    if spec.mixer == "attn":
-        y = attn_mod.attention(p["mixer"], h, aspec, positions=positions)
-    elif spec.mixer == "cross_attn":
-        kv_pos = jnp.broadcast_to(
-            jnp.arange(cross_kv.shape[1], dtype=jnp.int32), cross_kv.shape[:2]
-        )
-        y = attn_mod.attention(
-            p["mixer"], h, aspec, positions=positions,
-            kv_x=cross_kv.astype(h.dtype), kv_positions=kv_pos,
-        )
-    elif spec.mixer == "attn+cross":
-        y = attn_mod.attention(p["mixer"], h, aspec, positions=positions)
-        if spec.post_norm:
+    with jax.named_scope(scopes.ATTENTION):
+        h = _norm(cfg, p["norm1"], x)
+        aspec = _attn_spec(cfg, spec, causal=causal)
+        if spec.mixer == "attn":
+            y = attn_mod.attention(p["mixer"], h, aspec, positions=positions)
+        elif spec.mixer == "cross_attn":
+            kv_pos = jnp.broadcast_to(
+                jnp.arange(cross_kv.shape[1], dtype=jnp.int32),
+                cross_kv.shape[:2])
+            y = attn_mod.attention(
+                p["mixer"], h, aspec, positions=positions,
+                kv_x=cross_kv.astype(h.dtype), kv_positions=kv_pos,
+            )
+        elif spec.mixer == "attn+cross":
+            y = attn_mod.attention(p["mixer"], h, aspec, positions=positions)
+            if spec.post_norm:
+                y = _norm(cfg, p["norm_post1"], y)
+            x = x + y
+            h = _norm(cfg, p["norm_cross"], x)
+            kv_pos = jnp.broadcast_to(
+                jnp.arange(cross_kv.shape[1], dtype=jnp.int32),
+                cross_kv.shape[:2])
+            y = attn_mod.attention(
+                p["cross"], h, aspec, positions=positions,
+                kv_x=cross_kv.astype(h.dtype), kv_positions=kv_pos,
+            )
+        elif spec.mixer == "mamba":
+            y = mamba_mod.mamba(p["mixer"], h, d_state=cfg.mamba_d_state,
+                                d_conv=cfg.mamba_d_conv)
+        elif spec.mixer == "rwkv":
+            y = rwkv_mod.time_mix(p["mixer"], h, head_size=cfg.rwkv_head_size)
+        else:
+            raise ValueError(spec.mixer)
+        if spec.post_norm and spec.mixer != "attn+cross":
             y = _norm(cfg, p["norm_post1"], y)
         x = x + y
-        h = _norm(cfg, p["norm_cross"], x)
-        kv_pos = jnp.broadcast_to(
-            jnp.arange(cross_kv.shape[1], dtype=jnp.int32), cross_kv.shape[:2]
-        )
-        y = attn_mod.attention(
-            p["cross"], h, aspec, positions=positions,
-            kv_x=cross_kv.astype(h.dtype), kv_positions=kv_pos,
-        )
-    elif spec.mixer == "mamba":
-        y = mamba_mod.mamba(p["mixer"], h, d_state=cfg.mamba_d_state,
-                            d_conv=cfg.mamba_d_conv)
-    elif spec.mixer == "rwkv":
-        y = rwkv_mod.time_mix(p["mixer"], h, head_size=cfg.rwkv_head_size)
-    else:
-        raise ValueError(spec.mixer)
-    if spec.post_norm and spec.mixer != "attn+cross":
-        y = _norm(cfg, p["norm_post1"], y)
-    x = x + y
     x, ffn_aux, _ = _ffn_block(cfg, spec, p, x, mode="seq")
     return x, aux + ffn_aux
 
@@ -274,13 +279,14 @@ def _hidden(params, cfg: ArchConfig, tokens, *, context=None,
             compute_dtype=jnp.bfloat16, remat=True):
     """Backbone forward up to the final norm. Returns (x (B,S,D), moe_aux)."""
     B, S = tokens.shape
-    x = params["embed"][tokens].astype(compute_dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), compute_dtype)
-    x = act.shard_batch_act(x)
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    if cfg.pos_embed == "learned":
-        x = x + params["pos"][:S][None].astype(compute_dtype)
+    with jax.named_scope(scopes.EMBED):
+        x = params["embed"][tokens].astype(compute_dtype)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), compute_dtype)
+        x = act.shard_batch_act(x)
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        if cfg.pos_embed == "learned":
+            x = x + params["pos"][:S][None].astype(compute_dtype)
 
     cross_kv = None
     if cfg.encoder is not None:
@@ -290,7 +296,8 @@ def _hidden(params, cfg: ArchConfig, tokens, *, context=None,
 
     x, aux = _run_blocks(params, cfg, x, positions=positions,
                          cross_kv=cross_kv, remat=remat)
-    return _norm(cfg, params["final_norm"], x), aux
+    with jax.named_scope(scopes.HEAD):
+        return _norm(cfg, params["final_norm"], x), aux
 
 
 def forward(params, cfg: ArchConfig, tokens, *, context=None,
@@ -319,39 +326,41 @@ def loss_fn(params, cfg: ArchConfig, batch, *, compute_dtype=jnp.bfloat16,
         params, cfg, batch["tokens"], context=batch.get("context"),
         compute_dtype=compute_dtype, remat=remat,
     )
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(compute_dtype)
-    labels = batch["labels"]
-    B, S, _ = x.shape
-    C = LOSS_CHUNK if (S % LOSS_CHUNK == 0 and S > LOSS_CHUNK) else S
-    nc = S // C
+    with jax.named_scope(scopes.HEAD):
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"]).astype(compute_dtype)
+        labels = batch["labels"]
+        B, S, _ = x.shape
+        C = LOSS_CHUNK if (S % LOSS_CHUNK == 0 and S > LOSS_CHUNK) else S
+        nc = S // C
 
-    @jax.checkpoint
-    def chunk(carry, inp):
-        nll_sum, n = carry
-        x_c, y_c = inp                                   # (B,C,D), (B,C)
-        logits = x_c @ head
-        logits = act.shard_logits(logits)
-        if cfg.final_softcap:
-            logits = softcap(logits.astype(jnp.float32), cfg.final_softcap)
-        logits = logits.astype(jnp.float32)
-        mask = y_c >= 0
-        safe = jnp.maximum(y_c, 0)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
-        nll_sum = nll_sum + (((logz - gold) * mask).sum())
-        return (nll_sum, n + mask.sum()), None
+        @jax.checkpoint
+        def chunk(carry, inp):
+            nll_sum, n = carry
+            x_c, y_c = inp                                   # (B,C,D), (B,C)
+            logits = x_c @ head
+            logits = act.shard_logits(logits)
+            if cfg.final_softcap:
+                logits = softcap(logits.astype(jnp.float32), cfg.final_softcap)
+            logits = logits.astype(jnp.float32)
+            mask = y_c >= 0
+            safe = jnp.maximum(y_c, 0)
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, safe[..., None],
+                                       axis=-1)[..., 0]
+            nll_sum = nll_sum + (((logz - gold) * mask).sum())
+            return (nll_sum, n + mask.sum()), None
 
-    xs = (
-        jnp.moveaxis(x.reshape(B, nc, C, -1), 1, 0),
-        jnp.moveaxis(labels.reshape(B, nc, C), 1, 0),
-    )
-    (nll, n), _ = jax.lax.scan(
-        chunk, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)), xs
-    )
-    loss = nll / jnp.maximum(n, 1)
-    if cfg.has_moe:
-        loss = loss + MOE_AUX_COEF * aux / cfg.num_layers
+        xs = (
+            jnp.moveaxis(x.reshape(B, nc, C, -1), 1, 0),
+            jnp.moveaxis(labels.reshape(B, nc, C), 1, 0),
+        )
+        (nll, n), _ = jax.lax.scan(
+            chunk, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)), xs
+        )
+        loss = nll / jnp.maximum(n, 1)
+        if cfg.has_moe:
+            loss = loss + MOE_AUX_COEF * aux / cfg.num_layers
     return loss
 
 

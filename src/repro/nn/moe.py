@@ -14,6 +14,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 
 def init_dense_ffn(key, d_model: int, d_ff: int):
     k1, k2, k3 = jax.random.split(key, 3)
@@ -135,41 +137,46 @@ def moe_ffn(p, x, *, top_k: int, capacity_factor: float = 1.25,
     K = top_k
     C = moe_capacity(S, E, K, capacity_factor)               # per group
 
-    probs, gate, eid_f, pos, keep = moe_route(p["router"], x, top_k=K,
-                                              capacity=C)
-    safe_pos = jnp.where(keep, pos, 0)
+    with jax.named_scope(scopes.ROUTER):
+        probs, gate, eid_f, pos, keep = moe_route(p["router"], x, top_k=K,
+                                                  capacity=C)
+        safe_pos = jnp.where(keep, pos, 0)
 
-    if impl == "ref":
-        buf = ref_dispatch(x, eid_f, safe_pos, keep, num_experts=E,
-                           capacity=C, top_k=K)              # (G, E, C, D)
-    else:
-        from repro.kernels import ops as kops
+    with jax.named_scope(scopes.DISPATCH):
+        if impl == "ref":
+            buf = ref_dispatch(x, eid_f, safe_pos, keep, num_experts=E,
+                               capacity=C, top_k=K)          # (G, E, C, D)
+        else:
+            from repro.kernels import ops as kops
 
-        buf = kops.moe_dispatch(x, eid_f, pos,
-                                keep.astype(jnp.float32),
-                                num_experts=E, capacity=C, top_k=K,
-                                impl=impl)
-    buf = shard_moe_group_buffer(buf)
+            buf = kops.moe_dispatch(x, eid_f, pos,
+                                    keep.astype(jnp.float32),
+                                    num_experts=E, capacity=C, top_k=K,
+                                    impl=impl)
+        buf = shard_moe_group_buffer(buf)
 
     # batched expert SwiGLU — the expert dim shards over the model axis
-    h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", buf, p["w1"]))
-    h = h * jnp.einsum("gecd,edf->gecf", buf, p["w3"])
-    out = jnp.einsum("gecf,efd->gecd", h, p["w2"])           # (G, E, C, D)
-    out = shard_moe_group_buffer(out)
+    with jax.named_scope(scopes.EXPERTS):
+        h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", buf, p["w1"]))
+        h = h * jnp.einsum("gecd,edf->gecf", buf, p["w3"])
+        out = jnp.einsum("gecf,efd->gecd", h, p["w2"])       # (G, E, C, D)
+        out = shard_moe_group_buffer(out)
 
-    w = (gate.reshape(B, S * K) * keep).astype(x.dtype)
-    if impl == "ref":
-        y = ref_combine(out, eid_f, safe_pos, w, top_k=K)
-    else:
-        y = kops.moe_combine(
-            out, eid_f.reshape(B, S, K), safe_pos.reshape(B, S, K),
-            w.reshape(B, S, K), impl=impl,
-        )
-    y = shard_batch_act(y)
+    with jax.named_scope(scopes.COMBINE):
+        w = (gate.reshape(B, S * K) * keep).astype(x.dtype)
+        if impl == "ref":
+            y = ref_combine(out, eid_f, safe_pos, w, top_k=K)
+        else:
+            y = kops.moe_combine(
+                out, eid_f.reshape(B, S, K), safe_pos.reshape(B, S, K),
+                w.reshape(B, S, K), impl=impl,
+            )
+        y = shard_batch_act(y)
 
     # Switch-style load-balance aux loss
-    eid = eid_f.reshape(B, S, K)
-    density = jax.nn.one_hot(eid[..., 0], E).mean((0, 1))    # top-1 share
-    mean_prob = probs.mean((0, 1))
-    aux = E * jnp.sum(density * mean_prob)
+    with jax.named_scope(scopes.ROUTER):
+        eid = eid_f.reshape(B, S, K)
+        density = jax.nn.one_hot(eid[..., 0], E).mean((0, 1))  # top-1 share
+        mean_prob = probs.mean((0, 1))
+        aux = E * jnp.sum(density * mean_prob)
     return y, {"aux_loss": aux, "dropped": 1.0 - keep.mean()}

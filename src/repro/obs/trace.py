@@ -24,19 +24,34 @@ in ``tests/test_obs.py``).
 
 Enabled state mirrors :mod:`repro.obs.metrics`: off by default, flipped
 by ``repro.obs.configure`` (which also sets ``REPRO_OBS`` so workers
-spawned afterwards inherit it).  Disabled, :func:`span` returns a shared
-no-op context manager — one branch + no allocation.
+spawned afterwards inherit it).
+
+A span has a second sink: while a JAX profiler session records, it also
+opens a profiler ``TraceMe`` of the same name, whether or not the ring
+is on, so the program's host spans land in the profile on the device
+ops' clock.  The profiler is taken only once jax has loaded it
+(``jax._src.lib`` in ``sys.modules``); this module never imports it.
+With both sinks off, :func:`span` returns a shared no-op context
+manager — one branch and no allocation beyond the profiler's check.
+
+Python's collector gets one ``python.gc`` span per collection, with its
+generation as an arg, from a ``gc.callbacks`` hook installed at import.
+It goes to the profile alone, never to the ring: a collection may start
+while its own thread holds the ring's lock.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 
 from repro.obs import metrics as _metrics
+from repro.obs import scopes
 
 #: default ring capacity — bounds trace memory on long runs (oldest
 #: events fall off; a serve/train session keeps the recent window)
@@ -56,6 +71,24 @@ def set_enabled(flag: bool) -> None:
 
 def now_us() -> float:
     return time.perf_counter_ns() / 1e3
+
+
+#: the profiler's ``TraceMe`` class, once jax is loaded
+_TraceMe = None
+
+
+def profiling() -> bool:
+    """Whether a JAX profiler session is recording.  False in a process
+    that has not loaded jax; never imports anything, so the collector's
+    hook may call it in the middle of an import."""
+    global _TraceMe
+    if _TraceMe is None:
+        profiler = getattr(sys.modules.get("jax._src.lib"), "_profiler",
+                           None)
+        if profiler is None:
+            return False
+        _TraceMe = profiler.TraceMe
+    return _TraceMe.is_enabled()
 
 
 class TraceBuffer:
@@ -111,7 +144,10 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "buf", "t0")
+    """Records into ``buf`` (None: the ring is off) and, while the
+    profiler records, into the profile."""
+
+    __slots__ = ("name", "cat", "args", "buf", "t0", "tm")
 
     def __init__(self, name, cat, buf, args):
         self.name = name
@@ -120,27 +156,59 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self.tm = _TraceMe(self.name, **self.args) if profiling() else None
+        if self.tm is not None:
+            self.tm.__enter__()
         self.t0 = now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = now_us()
-        self.buf.add({
-            "name": self.name, "cat": self.cat, "ph": "X",
-            "ts": self.t0, "dur": t1 - self.t0,
-            "pid": os.getpid(), "tid": threading.get_native_id(),
-            "args": self.args,
-        })
+        if self.tm is not None:
+            self.tm.__exit__(*exc)
+        if self.buf is not None:
+            self.buf.add({
+                "name": self.name, "cat": self.cat, "ph": "X",
+                "ts": self.t0, "dur": t1 - self.t0,
+                "pid": os.getpid(), "tid": threading.get_native_id(),
+                "args": self.args,
+            })
         return False
 
 
 def span(name: str, cat: str = "repro", *, buffer: TraceBuffer | None = None,
          **args):
     """``with span("serve.prefill", rid=3): ...`` — records a complete
-    event on exit.  Near-free when disabled (shared no-op object)."""
-    if not _enabled:
-        return _NOOP
-    return _Span(name, cat, buffer if buffer is not None else BUFFER, args)
+    event on exit.  Near-free when disabled and no profiler records
+    (shared no-op object)."""
+    if _enabled:
+        return _Span(name, cat, buffer if buffer is not None else BUFFER,
+                     args)
+    if profiling():
+        return _Span(name, cat, None, args)
+    return _NOOP
+
+
+_gc_span = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one ``python.gc`` span per collection, in
+    the profile only.  A collection can run while this thread holds the
+    ring's lock, so the hook never records into the ring."""
+    global _gc_span
+    if phase == "start":
+        if profiling():
+            _gc_span = _Span(scopes.PYTHON_GC, "python", None,
+                             {"generation": info["generation"]})
+            _gc_span.__enter__()
+    elif _gc_span is not None:
+        sp, _gc_span = _gc_span, None
+        sp.__exit__(None, None, None)
+
+
+if _on_gc not in gc.callbacks:
+    gc.callbacks.append(_on_gc)
 
 
 def instant(name: str, cat: str = "repro", *,

@@ -30,7 +30,7 @@ from hypothesis import given, settings, strategies as st
 from repro import obs
 from repro.core.profiles import B_O
 from repro.core.resources import CPU_CORE
-from repro.obs import metrics, trace
+from repro.obs import metrics, scopes, trace
 from repro.obs.bridge import apply_measured_odt, snapshot_resources
 from repro.ps.client import PSClient
 from repro.ps.telemetry import PSTelemetry
@@ -188,6 +188,87 @@ class TestTrace:
         assert len(lanes) >= 3
         for lane, ts in lanes.items():
             assert ts == sorted(ts), f"lane {lane} not monotonic"
+
+
+class TestProfilerSink:
+    def test_program_spans_reach_the_profiler_with_the_ring_off(
+            self, tmp_path):
+        """Under a profiler session the loader's and the collector's
+        spans land on the profile's host plane, with the obs ring off
+        and left empty."""
+        import gc
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        from repro.data import PrefetchLoader
+
+        was = trace.enabled()
+        trace.set_enabled(False)
+        trace.BUFFER.drain()
+        try:
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                loader = PrefetchLoader([{"x": np.zeros(4)}] * 3, depth=1)
+                assert len(list(loader)) == 3
+                loader.close()
+                gc.collect()
+            finally:
+                jax.profiler.stop_trace()
+            assert len(trace.BUFFER) == 0
+        finally:
+            trace.set_enabled(was)
+        (path,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+        names = {e.name for plane in ProfileData.from_file(path).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events}
+        assert {scopes.DATA_WAIT, scopes.DATA_PRODUCE, scopes.DATA_PUT_WAIT,
+                scopes.PYTHON_GC} <= names
+
+    def test_collection_while_the_ring_is_locked(self, tmp_path):
+        """A collection that starts while its thread holds the ring's lock
+        (as ``events()`` does while it copies) finishes: the collector's
+        hook never takes that lock, with the ring on and a profile
+        recording."""
+        import gc
+        import threading
+
+        import jax
+
+        def collect_under_lock():
+            with trace.BUFFER._lock:
+                gc.collect()
+
+        was = trace.enabled()
+        trace.set_enabled(True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert trace.profiling()
+            worker = threading.Thread(target=collect_under_lock, daemon=True)
+            worker.start()
+            worker.join(timeout=30)
+            assert not worker.is_alive(), "collection deadlocked on the ring"
+        finally:
+            jax.profiler.stop_trace()
+            trace.set_enabled(was)
+
+    def test_obs_stays_jax_free(self):
+        """Spans and the collector's hook never import jax into a
+        process that has not (the PS shard worker's import path)."""
+        import subprocess
+        import sys
+
+        root = os.path.join(os.path.dirname(__file__), "..")
+        code = ("import gc, sys; import repro.obs as obs; "
+                "obs.configure(enabled=True); gc.collect()\n"
+                "with obs.span('x'): pass\n"
+                "sys.exit(1 if 'jax' in sys.modules else 0)")
+        proc = subprocess.run(
+            [sys.executable, "-c", code],
+            env={**os.environ, "PYTHONPATH": os.path.join(root, "src")},
+            capture_output=True, timeout=60)
+        assert proc.returncode == 0, proc.stderr.decode()
 
 
 class TestPSTelemetryBitCompat:
