@@ -9,49 +9,40 @@ references.  ``auto`` picks per-backend.
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels import moe as moe_kernels
 from repro.kernels import paged_attention as paged_k
 from repro.kernels.embedding_bag import embedding_bag as _embedding_bag_kernel
-from repro.kernels.flash_attention import (
-    DEFAULT_BLOCK_K,
-    DEFAULT_BLOCK_Q,
-    flash_attention as _flash_kernel,
-)
+from repro.kernels.flash_attention import flash_attention as _flash_kernel
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def attn_impl(impl: str) -> str:
+    """Resolve the training attention impl: ``auto`` compiles the Pallas
+    flash kernel on TPU and keeps the XLA paths of ``nn.attention``
+    (``xla``) elsewhere; ``interpret`` executes the kernel bodies in the
+    Pallas interpreter."""
+    if impl == "auto":
+        return "pallas" if _on_tpu() else "xla"
+    if impl not in ("xla", "interpret", "pallas"):
+        raise ValueError(f"unknown attention impl {impl!r}: expected "
+                         "auto/xla/interpret/pallas")
+    return impl
+
+
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     softcap: float | None = None, impl: str = "auto"):
-    """Padded/validated entry point. q,k,v: (B, H, S, hd)."""
+    """q: (B, H, S, hd); k, v: (B, KV, S, hd), H a multiple of KV.
+    Differentiable; the kernel pads ragged lengths itself."""
     if impl == "ref" or (impl == "auto" and not _on_tpu()):
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                        softcap=softcap)
-    interpret = impl == "interpret"
-    B, H, Sq, hd = q.shape
-    Sk = k.shape[2]
-    bq = min(DEFAULT_BLOCK_Q, Sq)
-    bk = min(DEFAULT_BLOCK_K, Sk)
-    pq = (-Sq) % bq
-    pk = (-Sk) % bk
-    if pq:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, pq), (0, 0)))
-    if pk:
-        # padded keys land at positions > any query → masked out by causal;
-        # for non-causal, mask via window=None path needs explicit care, so
-        # only pad when causal or no padding needed.
-        assert causal, "non-causal needs Sk % block_k == 0"
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pk), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pk), (0, 0)))
-    out = _flash_kernel(q, k, v, causal=causal, window=window,
-                        softcap=softcap, block_q=bq, block_k=bk,
-                        interpret=interpret)
-    return out[:, :, :Sq]
+    return _flash_kernel(q, k, v, causal=causal, window=window,
+                         softcap=softcap, interpret=impl == "interpret")
 
 
 def embedding_bag(ids, table, *, impl: str = "auto"):

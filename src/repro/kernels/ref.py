@@ -13,9 +13,12 @@ NEG_INF = -1e30
 def flash_attention_ref(q, k, v, *, causal: bool = True,
                         window: int | None = None,
                         softcap: float | None = None):
-    """q, k, v: (B, H, S, hd) → (B, H, Sq, hd).  Direct softmax attention."""
+    """q: (B, H, Sq, hd); k, v: (B, KV, Sk, hd), each KV head serving
+    H // KV query heads → (B, H, Sq, hd).  Direct softmax attention."""
     B, H, Sq, hd = q.shape
     Sk = k.shape[2]
+    k = jnp.repeat(k, H // k.shape[1], axis=1)
+    v = jnp.repeat(v, H // v.shape[1], axis=1)
     scale = 1.0 / math.sqrt(hd)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale

@@ -59,6 +59,12 @@ class ArchConfig:
     #: scatter/gather oracle; "interpret"/"slot"/"pallas" force a path
     #: (see repro/kernels/moe.py)
     moe_impl: str = "auto"
+    #: training attention above ``nn.attention.BLOCKWISE_THRESHOLD``:
+    #: "auto" → the Pallas flash kernel (forward and backward) on TPU for
+    #: the calls it covers, the XLA blockwise scan elsewhere; "xla" pins
+    #: the XLA paths; "interpret"/"pallas" force the kernel (resolved by
+    #: kernels/ops.py ``attn_impl``)
+    attn_impl: str = "auto"
     #: decode KV-cache layout: "dense" = per-sequence ring buffers (the
     #: reference oracle); "paged" = shared page pool + per-sequence page
     #: tables (kernels/paged_attention.py) — within the paged path the

@@ -186,15 +186,18 @@ def _ffn_block(cfg, spec: LayerSpec, p, x, *, mode: str = "seq", cache=None):
 
 
 def _apply_layer(cfg, spec: LayerSpec, p, x, *, positions, cross_kv=None,
-                 causal=True):
-    """One layer forward. Returns (x, moe_aux)."""
+                 causal=True, arange_positions=False):
+    """One layer forward; ``arange_positions``: ``positions`` is
+    ``arange(S)`` in every row.  Returns (x, moe_aux)."""
     p = act.gather_params(_cast(p, x.dtype), cfg)
     aux = jnp.zeros((), jnp.float32)
     with jax.named_scope(scopes.ATTENTION):
         h = _norm(cfg, p["norm1"], x)
         aspec = _attn_spec(cfg, spec, causal=causal)
+        self_kw = dict(positions=positions, impl=cfg.attn_impl,
+                       arange_positions=arange_positions)
         if spec.mixer == "attn":
-            y = attn_mod.attention(p["mixer"], h, aspec, positions=positions)
+            y = attn_mod.attention(p["mixer"], h, aspec, **self_kw)
         elif spec.mixer == "cross_attn":
             kv_pos = jnp.broadcast_to(
                 jnp.arange(cross_kv.shape[1], dtype=jnp.int32),
@@ -204,7 +207,7 @@ def _apply_layer(cfg, spec: LayerSpec, p, x, *, positions, cross_kv=None,
                 kv_x=cross_kv.astype(h.dtype), kv_positions=kv_pos,
             )
         elif spec.mixer == "attn+cross":
-            y = attn_mod.attention(p["mixer"], h, aspec, positions=positions)
+            y = attn_mod.attention(p["mixer"], h, aspec, **self_kw)
             if spec.post_norm:
                 y = _norm(cfg, p["norm_post1"], y)
             x = x + y
@@ -231,7 +234,7 @@ def _apply_layer(cfg, spec: LayerSpec, p, x, *, positions, cross_kv=None,
 
 
 def _run_blocks(params, cfg: ArchConfig, x, *, positions, cross_kv=None,
-                remat=True):
+                remat=True, arange_positions=False):
     """Scan the stacked pattern blocks over ``repeats``."""
 
     def group(carry, block_slice):
@@ -239,7 +242,8 @@ def _run_blocks(params, cfg: ArchConfig, x, *, positions, cross_kv=None,
         for j, spec in enumerate(cfg.pattern):
             def layer(p, x, positions, cross_kv, *, _spec=spec):
                 return _apply_layer(cfg, _spec, p, x, positions=positions,
-                                    cross_kv=cross_kv)
+                                    cross_kv=cross_kv,
+                                    arange_positions=arange_positions)
 
             # per-LAYER remat: backward recomputes one layer at a time, so
             # wide mixer internals (Mamba scan states, MoE buffers) never
@@ -295,7 +299,8 @@ def _hidden(params, cfg: ArchConfig, tokens, *, context=None,
         cross_kv = context.astype(compute_dtype)
 
     x, aux = _run_blocks(params, cfg, x, positions=positions,
-                         cross_kv=cross_kv, remat=remat)
+                         cross_kv=cross_kv, remat=remat,
+                         arange_positions=True)
     with jax.named_scope(scopes.HEAD):
         return _norm(cfg, params["final_norm"], x), aux
 

@@ -1,14 +1,27 @@
 """GQA attention with RoPE, sliding window, logit soft-capping, cross-attn,
-KV-cache decode, and a blockwise (flash-style, online-softmax) path for long
-sequences — pure JAX.  The Pallas TPU kernel in ``repro.kernels`` implements
-the same blockwise algorithm for the MXU; this module is the XLA fallback
-and the numerical reference for shapes the kernel doesn't cover.
+KV-cache decode, and the training paths for long sequences.
 
-Sharding note: GQA is computed with KV heads *expanded* to the full head
-count before the score einsum, so one head axis (divisible by the 16-wide
-``model`` mesh axis for most archs) carries the tensor parallelism; the
-expansion is a broadcast XLA keeps fused.  The (KV, G) grouped form would
-leave both factors smaller than the mesh axis and drop head sharding.
+Which path :func:`attention` (training / prefill / encoder) takes:
+
+* S ≤ ``BLOCKWISE_THRESHOLD`` — ``_sdpa_direct``, the full score matrix
+  in XLA;
+* causal self-attention over plain ``arange`` positions above it, with
+  no soft-cap and no mesh, where ``kernels.ops.attn_impl`` resolves to
+  the Pallas flash kernel (TPU, or ``interpret`` in tests) —
+  ``kernels/flash_attention.py``, forward and backward, K/V unexpanded;
+* everything else above it (cross-attention, non-causal encoders,
+  soft-capped layers, CPU) — ``_sdpa_blockwise``, an XLA online-softmax
+  scan over key blocks.
+
+The obs counter ``attention.calls`` (labels ``path``, ``reason``) counts
+at trace time which path each call took and why it fell back.
+
+Sharding note: the XLA paths compute GQA with KV heads *expanded* to the
+full head count before the score einsum, so one head axis (divisible by
+the 16-wide ``model`` mesh axis for most archs) carries the tensor
+parallelism; the expansion is a broadcast XLA keeps fused.  The (KV, G)
+grouped form would leave both factors smaller than the mesh axis and
+drop head sharding.
 """
 
 from __future__ import annotations
@@ -19,6 +32,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ops as kernel_ops
 from repro.kernels import paged_attention as paged_k
 from repro.nn.base import apply_rope, rmsnorm, softcap
@@ -144,7 +158,7 @@ def _sdpa_blockwise(q, k, v, q_pos, k_pos, spec: AttnSpec):
     return jnp.moveaxis(out, 1, 2).astype(q.dtype)  # (B,Sq,H,hd)
 
 
-def _project_qkv(p, x, kv_x, spec: AttnSpec, q_pos, k_pos):
+def _project_qkv(p, x, kv_x, spec: AttnSpec, q_pos, k_pos, *, expand=True):
     B, Sq, _ = x.shape
     H, KV, hd = spec.n_heads, spec.n_kv_heads, spec.head_dim
     q = (x @ p["wq"]).reshape(B, Sq, H, hd)
@@ -156,28 +170,70 @@ def _project_qkv(p, x, kv_x, spec: AttnSpec, q_pos, k_pos):
     if spec.rope:
         q = apply_rope(q, q_pos, theta=spec.rope_theta, fraction=spec.rope_fraction)
         k = apply_rope(k, k_pos, theta=spec.rope_theta, fraction=spec.rope_fraction)
+    if not expand:
+        return q, k, v
     q = act.shard_heads(q, axis=2)
     k = act.shard_heads(_expand_kv(k, H), axis=2)
     v = act.shard_heads(_expand_kv(v, H), axis=2)
     return q, k, v
 
 
-def attention(p, x, spec: AttnSpec, *, positions, kv_x=None, kv_positions=None):
+def _flash_fallback(spec: AttnSpec, *, impl: str, seq: int, self_attn: bool,
+                    arange_positions: bool) -> str | None:
+    """Why a call cannot take the flash kernel, or None if it can
+    (``impl`` as ``kernels.ops.attn_impl`` resolves it)."""
+    if seq <= BLOCKWISE_THRESHOLD:
+        return "short"
+    if impl == "xla":
+        return "backend"
+    if not self_attn:
+        return "cross"
+    if not spec.causal:
+        return "non_causal"
+    if spec.logit_softcap:
+        return "softcap"
+    if not arange_positions:
+        return "positions"
+    if act.current_mesh() is not None:
+        return "mesh"
+    return None
+
+
+def attention(p, x, spec: AttnSpec, *, positions, kv_x=None, kv_positions=None,
+              arange_positions: bool = False, impl: str = "auto"):
     """Full-sequence attention (training / prefill / encoder).
 
     x: (B, Sq, D); kv_x: cross-attention source (B, Sk, Dkv) or None.
-    positions: (B, Sq) int32.  Returns (B, Sq, D).
+    positions: (B, Sq) int32; ``arange_positions`` is the caller's word
+    that they are ``arange(Sq)`` in every row (the flash kernel masks by
+    index, not by ``positions``).  ``impl``: ``ArchConfig.attn_impl``.
+    Returns (B, Sq, D).
     """
     self_attn = kv_x is None
     kv_x = x if self_attn else kv_x
     k_pos = positions if self_attn else kv_positions
-    q, k, v = _project_qkv(p, x, kv_x, spec, positions, k_pos)
-    Sk = k.shape[1]
-    if max(x.shape[1], Sk) <= BLOCKWISE_THRESHOLD:
-        o = _sdpa_direct(q, k, v, positions, k_pos, spec)
-    else:
-        o = _sdpa_blockwise(q, k, v, positions, k_pos, spec)
     B, Sq = x.shape[:2]
+    impl = kernel_ops.attn_impl(impl)
+    reason = _flash_fallback(spec, impl=impl, seq=max(Sq, kv_x.shape[1]),
+                             self_attn=self_attn,
+                             arange_positions=arange_positions)
+    if reason is None:
+        obs.REGISTRY.counter("attention.calls", path="flash").inc()
+        q, k, v = _project_qkv(p, x, kv_x, spec, positions, k_pos,
+                               expand=False)
+        o = kernel_ops.flash_attention(
+            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+            jnp.swapaxes(v, 1, 2), causal=True, window=spec.window,
+            impl=impl)
+        o = jnp.swapaxes(o, 1, 2)
+    else:
+        obs.REGISTRY.counter("attention.calls", path="xla",
+                             reason=reason).inc()
+        q, k, v = _project_qkv(p, x, kv_x, spec, positions, k_pos)
+        if reason == "short":
+            o = _sdpa_direct(q, k, v, positions, k_pos, spec)
+        else:
+            o = _sdpa_blockwise(q, k, v, positions, k_pos, spec)
     return o.reshape(B, Sq, spec.n_heads * spec.head_dim) @ p["wo"]
 
 

@@ -1,5 +1,7 @@
 """Pallas kernel validation: interpret-mode sweep vs the jnp oracles."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,10 +11,12 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels import moe as moe_k
 from repro.kernels import ref
 from repro.kernels.embedding_bag import embedding_bag
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import Blocks, flash_attention
 from repro.nn import moe as moe_mod
 
 KEY = jax.random.PRNGKey(0)
+#: 128 x 128 blocks in every kernel: several blocks at small lengths
+B128 = Blocks((128, 128), (128, 128), (128, 128))
 
 
 def _qkv(B, H, Sq, Sk, hd, dtype):
@@ -29,7 +33,7 @@ class TestFlashAttention:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_shape_dtype_sweep_causal(self, B, H, S, hd, dtype):
         q, k, v = _qkv(B, H, S, S, hd, dtype)
-        out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
+        out = flash_attention(q, k, v, causal=True, blocks=B128,
                               interpret=True)
         want = ref.flash_attention_ref(q, k, v, causal=True)
         tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
@@ -78,6 +82,209 @@ class TestFlashAttention:
         out_pl = flash_attention(q, k, v, causal=True, interpret=True)
         np.testing.assert_allclose(np.asarray(out_xla), np.asarray(out_pl),
                                    atol=3e-5)
+
+
+def _gqa(H, KV, S, hd, dtype):
+    q = jax.random.normal(jax.random.fold_in(KEY, 1), (1, H, S, hd), dtype)
+    k = jax.random.normal(jax.random.fold_in(KEY, 2), (1, KV, S, hd), dtype)
+    v = jax.random.normal(jax.random.fold_in(KEY, 3), (1, KV, S, hd), dtype)
+    g = jax.random.normal(jax.random.fold_in(KEY, 4), (1, H, S, hd), dtype)
+    return q, k, v, g
+
+
+def _rel_err(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return np.max(np.abs(got - want)) / np.max(np.abs(want))
+
+
+class TestFlashAttentionGrad:
+    """Forward and ``jax.grad`` (dq, dk, dv) of the kernel against the
+    direct oracle, over GQA ratios, window, dtype and ragged lengths."""
+
+    def _check(self, H, KV, S, dtype, *, causal=True, window=None, hd=64):
+        q, k, v, g = _gqa(H, KV, S, hd, dtype)
+        f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+
+        def loss(fn, q, k, v):
+            o = fn(q, k, v, causal=causal, window=window)
+            return jnp.sum(f32(o) * f32(g))
+
+        kern = functools.partial(flash_attention, blocks=B128,
+                                 interpret=True)
+        want_o = ref.flash_attention_ref(f32(q), f32(k), f32(v),
+                                         causal=causal, window=window)
+        got_o = kern(q, k, v, causal=causal, window=window)
+        got = jax.grad(functools.partial(loss, kern), (0, 1, 2))(q, k, v)
+        want = jax.grad(functools.partial(loss, ref.flash_attention_ref),
+                        (0, 1, 2))(f32(q), f32(k), f32(v))
+        tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+        assert got_o.shape == q.shape and got_o.dtype == dtype
+        assert _rel_err(got_o, want_o) < tol
+        for name, a, b in zip(("dq", "dk", "dv"), got, want):
+            assert a.shape == b.shape, name
+            assert _rel_err(a, b) < tol, name
+
+    @pytest.mark.parametrize("H,KV", [(2, 2), (4, 1), (16, 1)],
+                             ids=["mha", "gqa4", "gqa16"])
+    @pytest.mark.parametrize("window", [None, 96])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_matches_reference(self, H, KV, window, dtype):
+        self._check(H, KV, 256, dtype, window=window)
+
+    @pytest.mark.parametrize("S,causal", [(200, True), (300, True),
+                                          (200, False)])
+    def test_ragged_length_is_padded(self, S, causal):
+        self._check(4, 2, S, jnp.float32, causal=causal)
+
+    def test_per_kernel_blocks(self):
+        """Forward, dQ and dK/dV may tile differently."""
+        q, k, v, g = _gqa(4, 2, 512, 128, jnp.float32)
+        blocks = Blocks(fwd=(256, 128), dq=(128, 256), dkv=(256, 128))
+
+        def loss(fn, q, k, v):
+            return jnp.sum(fn(q, k, v, causal=True, window=130) * g)
+
+        kern = functools.partial(flash_attention, blocks=blocks,
+                                 interpret=True)
+        got = jax.grad(functools.partial(loss, kern), (0, 1, 2))(q, k, v)
+        want = jax.grad(functools.partial(loss, ref.flash_attention_ref),
+                        (0, 1, 2))(q, k, v)
+        for a, b in zip(got, want):
+            assert _rel_err(a, b) < 2e-5
+
+    def test_softcap_has_no_backward(self):
+        q, k, v, _ = _gqa(2, 2, 128, 64, jnp.float32)
+        with pytest.raises(NotImplementedError):
+            jax.grad(lambda q: flash_attention(
+                q, k, v, softcap=30.0, interpret=True).sum())(q)
+
+
+@pytest.fixture
+def attn_calls():
+    """Turns the obs registry on; yields a reader of the
+    ``attention.calls`` counts added since the test began."""
+    from repro import obs
+
+    reg = obs.REGISTRY
+    was = reg.enabled
+    reg.enabled = True
+
+    def counts():
+        return {tuple(sorted(lab.items())): m.value
+                for lab, m in reg.find("attention.calls")}
+
+    before = counts()
+
+    def added():
+        return {k: v - before.get(k, 0.0) for k, v in counts().items()
+                if v != before.get(k, 0.0)}
+
+    yield added
+    reg.enabled = was
+
+
+FLASH = (("path", "flash"),)
+
+
+def _xla(reason):
+    return (("path", "xla"), ("reason", reason))
+
+
+class TestAttentionRouting:
+    """``nn.attention.attention`` takes the kernel only for causal
+    self-attention over ``arange`` positions above the threshold."""
+
+    D, S = 64, 256
+
+    @pytest.fixture(autouse=True)
+    def low_threshold(self, monkeypatch):
+        from repro.nn import attention as attn_mod
+
+        monkeypatch.setattr(attn_mod, "BLOCKWISE_THRESHOLD", 128)
+
+    def _layer(self, **kw):
+        from repro.nn import attention as attn_mod
+
+        d = dict(n_heads=4, n_kv_heads=2, head_dim=16, causal=True, rope=True,
+                 qk_norm=True)
+        d.update(kw)
+        spec = attn_mod.AttnSpec(**d)
+        p = attn_mod.init_attention(KEY, self.D, spec)
+        x = jax.random.normal(jax.random.fold_in(KEY, 5), (2, self.S, self.D))
+        pos = jnp.broadcast_to(jnp.arange(self.S, dtype=jnp.int32),
+                               (2, self.S))
+        return attn_mod, spec, p, x, pos
+
+    @pytest.mark.parametrize("window", [None, 100])
+    def test_kernel_matches_blockwise(self, attn_calls, window):
+        """Value and gradient (params and input) of the kernel path equal
+        the XLA blockwise path's."""
+        attn_mod, spec, p, x, pos = self._layer(window=window)
+
+        def loss(impl, p, x):
+            y = attn_mod.attention(p, x, spec, positions=pos, impl=impl,
+                                   arange_positions=True)
+            return jnp.sum(jnp.sin(y))
+
+        lk, gk = jax.value_and_grad(functools.partial(loss, "interpret"),
+                                    (0, 1))(p, x)
+        lx, gx = jax.value_and_grad(functools.partial(loss, "xla"),
+                                    (0, 1))(p, x)
+        assert attn_calls() == {FLASH: 1, _xla("backend"): 1}
+        np.testing.assert_allclose(float(lk), float(lx), rtol=1e-5)
+        for a, b in zip(jax.tree.leaves(gk), jax.tree.leaves(gx)):
+            assert _rel_err(a, b) < 1e-4
+
+    @pytest.mark.parametrize("reason", ["cross", "non_causal", "softcap",
+                                        "positions", "short"])
+    def test_fallback_keeps_xla_path(self, attn_calls, reason):
+        """A call the kernel does not cover runs the XLA path bit for bit
+        and is counted with its reason."""
+        attn_mod, spec, p, x, pos = self._layer(
+            causal=reason != "non_causal",
+            logit_softcap=30.0 if reason == "softcap" else None)
+        if reason == "short":
+            x, pos = x[:, :100], pos[:, :100]
+        kw = dict(positions=pos, arange_positions=reason != "positions")
+        if reason == "cross":
+            kw.update(kv_x=x[:, ::-1], kv_positions=pos)
+        got = attn_mod.attention(p, x, spec, impl="interpret", **kw)
+        want = attn_mod.attention(p, x, spec, impl="xla", **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        want_calls = {_xla(reason): 2}
+        if reason != "short":
+            want_calls = {_xla(reason): 1, _xla("backend"): 1}
+        assert attn_calls() == want_calls
+
+    @pytest.mark.parametrize("cell", ["chatglm3-6b-32k.train-8k",
+                                      "olmoe-1b-7b.train-4k"])
+    def test_benchmark_train_steps_take_kernel(self, attn_calls, cell,
+                                               monkeypatch):
+        """Every attention call of the benchmark configurations' train
+        steps traces onto the kernel at the cells' own shapes."""
+        import dataclasses
+
+        from benchmarks.chip import model, spec
+        from repro.launch.steps import make_train_step
+        from repro.models import decoder
+        from repro.nn import attention as attn_mod
+        from repro.optim import adamw_init
+
+        monkeypatch.setattr(attn_mod, "BLOCKWISE_THRESHOLD", 2048)
+        bench = spec.Benchmark()
+        c = bench.cell(cell)
+        mix = bench.traffic(c["traffic"])
+        arch = dataclasses.replace(model.arch_config(
+            bench.config(c["config"]), bench.reference(c["config"]),
+            bench.adapter(c["config"])), attn_impl="interpret")
+        p = jax.eval_shape(lambda k: decoder.init_model(arch, k), KEY)
+        rows = (mix["sequences_per_step"], mix["sequence_length"])
+        batch = {"tokens": jax.ShapeDtypeStruct(rows, jnp.int32),
+                 "labels": jax.ShapeDtypeStruct(rows, jnp.int32)}
+        jax.eval_shape(make_train_step(arch, microbatch=mix["microbatch"]),
+                       p, jax.eval_shape(adamw_init, p), batch)
+        calls = attn_calls()
+        assert set(calls) == {FLASH} and calls[FLASH] >= arch.num_layers
 
 
 class TestEmbeddingBag:

@@ -7,6 +7,8 @@ refuses; these tests catch that without a chip.  Each asserts that the
 compiled program holds the kernel (``tpu_custom_call``).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -111,3 +113,17 @@ class TestKernelsCompileForV5e:
             sh, dt, sharding=one_chip)
         qkv = [s((1, 32, 1024, 64))] * 3
         assert "tpu_custom_call" in _compile(flash_attention, *qkv)
+
+    @pytest.mark.parametrize("H,KV,S", [(32, 2, 8192), (16, 16, 4096)],
+                             ids=["chatglm3-6b-32k", "olmoe-1b-7b"])
+    def test_flash_attention_train(self, one_chip, H, KV, S):
+        """``jax.grad`` of the kernel at the training cells' widths: the
+        forward, dQ and dK/dV passes are each a kernel."""
+        s = lambda sh: jax.ShapeDtypeStruct(  # noqa: E731
+            sh, jnp.bfloat16, sharding=one_chip)
+        loss = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v).astype(jnp.float32).sum()
+        hlo = _compile(jax.grad(loss, (0, 1, 2)), s((1, H, S, 128)),
+                       s((1, KV, S, 128)), s((1, KV, S, 128)))
+        for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+            assert re.search(rf"%{name}[.0-9]* = .*tpu_custom_call", hlo), name
