@@ -9,7 +9,8 @@ For each of ``--seeds`` the program's compared numbers (the lower
 reading); for each of ``--control-seeds`` those of the reference
 computed with float8 (e4m3) matmul operands in the program's place (the
 control, which has to fail); for each of ``--fault-seeds`` those of the
-program trained on half of each batch, the mean taken over the rest.
+program trained on half of each batch (of a one-row batch, half of its
+tokens), the mean taken over the rest.
 Prints one JSON line per reading, with the verdict of the cell's
 committed limits on it, and writes them all to ``--out``.
 """
@@ -28,8 +29,9 @@ sys.path[:] = [p for p in sys.path if Path(p or ".").resolve() != HERE]
 
 
 class HalfBatch:
-    """A traffic source whose batches keep only their first half of rows:
-    the program then takes its mean over the rest."""
+    """A traffic source whose batches keep only their first half of rows,
+    or of a lone row's tokens: the program then takes its mean over the
+    rest."""
 
     def __init__(self, source):
         self.source = source
@@ -37,8 +39,10 @@ class HalfBatch:
 
     def batch(self, i):
         b = self.source.batch(i)
-        half = b["tokens"].shape[0] // 2
-        return {k: v[:half] for k, v in b.items()}
+        rows, seq = b["tokens"].shape
+        if rows > 1:
+            return {k: v[:rows // 2] for k, v in b.items()}
+        return {k: v[:, :seq // 2] for k, v in b.items()}
 
     def __iter__(self):
         i = 0

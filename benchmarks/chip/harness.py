@@ -57,14 +57,17 @@ class Run:
 
 class _CompileLog:
     """Times at which JAX built an executable (compiled or loaded one
-    from the persistent cache)."""
+    from the persistent cache), and the seconds of each of JAX's timed
+    events (tracing, lowering, compiling, cache reads) summed by name."""
 
     def __init__(self):
         self.times: list[float] = []
+        self.seconds: dict[str, float] = {}
 
     def __call__(self, event, duration, **_):
         if event == COMPILE_EVENT:
             self.times.append(time.perf_counter())
+        self.seconds[event] = self.seconds.get(event, 0.0) + duration
 
     def __enter__(self):
         jax.monitoring.register_event_duration_secs_listener(self)
@@ -80,13 +83,15 @@ def memory_stat(device, name: str) -> int:
     return (device.memory_stats() or {}).get(name, 0)
 
 
-def setup(program, source, key):
+def setup(program, source, key, mark=lambda name: None):
     """Initial state and the checked steps.  Returns the state after the
-    set-up steps, the loader, and the program's readings."""
+    set-up steps, the loader, and the program's readings; ``mark(name)``
+    is called as each part ends."""
     from repro.data import PrefetchLoader
 
     loader = PrefetchLoader(source, depth=2)
     params, opt_state = program.init_state(key)
+    mark("state")
     readings = {"loss": []}
     for i in range(CHECKED_STEPS + WARMUP_STEPS):
         params, opt_state, m = program.step(params, opt_state, next(loader))
@@ -97,7 +102,17 @@ def setup(program, source, key):
             readings["grad"] = program.grad_norms(opt_state)
         if i == CHECKED_STEPS - 1:
             readings["delta"] = program.delta_norms(params, key)
+        mark(f"step{i + 1}")
     return params, opt_state, loader, readings
+
+
+def print_split(label: str, marks: list, seconds: dict) -> None:
+    """One line on stderr: where a phase's time went, as the process's
+    age at the end of each part and the seconds of JAX's timed events."""
+    parts = " ".join(f"{name} {age:.2f}" for name, age in marks)
+    events = " ".join(f"{name.rsplit('/', 1)[-1]} {s:.2f}"
+                      for name, s in sorted(seconds.items()))
+    print(f"{label} {parts} | {events}", file=sys.stderr)
 
 
 def window(program, params, opt_state, loader, seconds, run: Run):
@@ -140,17 +155,21 @@ def run_cell(bench, cell: dict, *, seed: int, seconds: float, traced: bool,
               flops_per_token=train_flops_per_token(
                   reference, reference.dims(cfg), mix["sequence_length"]))
 
+    marks = [("devices", process_age())]
     with _CompileLog() as compiles:
         program = model.Program(cfg, mix, reference,
                                 bench.adapter(cell["config"]))
         source = TrafficSource(mix, program.arch.vocab, seed)
         key = seed_key(seed)
-        params, opt_state, loader, run.readings = setup(program, source, key)
+        params, opt_state, loader, run.readings = setup(
+            program, source, key,
+            lambda name: marks.append((name, process_age())))
         # what set-up left on the heap stays out of the collector's
         # passes inside the window
         gc.collect()
         gc.freeze()
         run.setup_s = process_age()
+        print_split("setup_split", marks, compiles.seconds)
         box: list = []
         try:
             if traced:
@@ -176,9 +195,12 @@ def run_cell(bench, cell: dict, *, seed: int, seconds: float, traced: bool,
         run.trace = trace.reduce(box.pop())
 
     t_ref = time.perf_counter()
-    ref = model.reference_readings(cfg, mix, reference, key,
-                                   [source.batch(i) for i in range(CHECKED_STEPS)])
+    with _CompileLog() as ref_events:
+        ref = model.reference_readings(
+            cfg, mix, reference, key,
+            [source.batch(i) for i in range(CHECKED_STEPS)])
     print(f"reference_s {time.perf_counter() - t_ref:.1f}", file=sys.stderr)
+    print_split("reference_split", [], ref_events.seconds)
     numbers = check.gaps(run.readings, ref)
     correct = check.verdict(numbers, limits) and run.failed == 0
 

@@ -27,34 +27,107 @@ def arch_config(cfg: dict, reference, adapter):
     return arch
 
 
-def to_program(ref: dict, adapter) -> dict:
-    """Reference layout → the program's: top-level leaves as they are, the
-    layers stacked on a leading axis into the one pattern block."""
+def _top_paths(adapter) -> dict:
+    """Top-level reference leaf name → its path in the program's tree
+    (``TOP_PATHS`` of the adapter; by default ``TOP_LEAVES`` at the top,
+    as they are)."""
+    return getattr(adapter, "TOP_PATHS",
+                   None) or {k: (k,) for k in adapter.TOP_LEAVES}
+
+
+def _put(tree: dict, path, value) -> None:
+    node = tree
+    for key in path[:-1]:
+        node = node.setdefault(key, {})
+    if path[-1] in node:
+        raise ValueError(f"two reference leaves fill program path {path}")
+    node[path[-1]] = value
+
+
+def _block(layers: list[dict], adapter) -> dict:
+    """One pattern block: its layers' leaves, stacked on a leading axis
+    in repeat order, at the paths ``LAYER_PATHS`` gives."""
+    names = list(layers[0])
+    if any(set(lp) != set(names) for lp in layers):
+        raise ValueError("the layers of one pattern position differ in "
+                         "their leaves")
     block: dict = {}
-    for name in ref["layers"][0]:
-        path = adapter.LAYER_PATHS[name]
-        node = block
-        for key in path[:-1]:
-            node = node.setdefault(key, {})
-        node[path[-1]] = jnp.stack([lp[name] for lp in ref["layers"]])
-    out = {k: ref[k] for k in adapter.TOP_LEAVES}
-    out["blocks"] = (block,)
+    for name in names:
+        if name not in adapter.LAYER_PATHS:
+            raise ValueError(f"reference layer leaf {name!r} has no place "
+                             "in the program's tree")
+        _put(block, adapter.LAYER_PATHS[name],
+             jnp.stack([lp[name] for lp in layers]))
+    return block
+
+
+def to_program(ref: dict, adapter, period: int) -> dict:
+    """Reference layout → the program's, as ``decoder.init_model`` lays it
+    out: each top-level leaf at its path, and reference layer ``i`` at
+    repeat ``i // period`` of pattern block ``i % period``, where
+    ``period`` is the length of the ArchConfig's pattern.  Each layer is
+    built from its own leaf names."""
+    tops = _top_paths(adapter)
+    unplaced = set(ref) - {"layers"} - set(tops)
+    if unplaced:
+        raise ValueError(f"reference leaves {sorted(unplaced)} have no place "
+                         "in the program's tree")
+    layers = ref["layers"]
+    if len(layers) % period:
+        raise ValueError(f"{len(layers)} layers do not repeat a pattern of "
+                         f"{period}")
+    out: dict = {}
+    for name, path in tops.items():
+        _put(out, path, ref[name])
+    out["blocks"] = tuple(_block(layers[j::period], adapter)
+                          for j in range(period))
     return out
+
+
+def _at(tree, path):
+    """The node at ``path``, or None where the tree has none."""
+    node = tree
+    for key in path:
+        try:
+            node = node[key]
+        except (KeyError, IndexError, TypeError):
+            return None
+    return node
+
+
+def _plain(path) -> tuple:
+    return tuple(getattr(k, "key", getattr(k, "idx", k)) for k in path)
 
 
 def program_leaves(tree: dict, n_layers: int, adapter) -> dict:
     """Leaves of a program-layout tree keyed by the reference's leaf names
-    (``layers.<i>.<name>`` for each layer)."""
-    out = {k: tree[k] for k in adapter.TOP_LEAVES}
-    block = tree["blocks"][0]
+    (``layers.<i>.<name>`` for each layer), read back through the layout
+    that ``to_program`` fills, with the period that the tree's pattern
+    blocks give.  Every leaf of the tree, and every row of a stacked one,
+    is read, or this fails."""
+    period = len(tree["blocks"])
+    repeats = n_layers // period
+    out, read = {}, set()
+    for name, path in _top_paths(adapter).items():
+        out[name] = _at(tree, path)
+        read.add(tuple(path))
     for name, path in adapter.LAYER_PATHS.items():
-        node = block
-        for key in path:
-            node = node.get(key) if isinstance(node, dict) else None
-        if node is None:
-            continue
-        for i in range(n_layers):
-            out[f"layers.{i}.{name}"] = node[i]
+        for j in range(period):
+            node = _at(tree, ("blocks", j, *path))
+            if node is None:
+                continue
+            read.add(("blocks", j, *path))
+            if repeats * period != n_layers or node.shape[0] != repeats:
+                raise ValueError(f"program leaf {('blocks', j, *path)} "
+                                 f"stacks {node.shape[0]} layers, not "
+                                 f"{n_layers} / {period}")
+            for r in range(repeats):
+                out[f"layers.{r * period + j}.{name}"] = node[r]
+    held = {_plain(p) for p, _ in jax.tree_util.tree_leaves_with_path(tree)}
+    if held != read:
+        raise ValueError(
+            f"program leaf not read: {sorted(held - read, key=str)}; "
+            f"not in the tree: {sorted(read - held, key=str)}")
     return out
 
 
@@ -76,7 +149,7 @@ class Program:
         self.reference, self.adapter = reference, adapter
         self.arch = arch_config(cfg, reference, adapter)
         self.opt = cfg["assumed"]["optimizer"]["value"]
-        self.n_layers = self.arch.num_layers
+        self.n_layers = reference.dims(cfg)["layers"]
         self.step = jax.jit(
             make_train_step(self.arch, lr=self.opt["lr"],
                             weight_decay=self.opt["weight_decay"],
@@ -92,7 +165,7 @@ class Program:
 
     def initial_params(self, key):
         return to_program(self.reference.init_params(key, self.cfg),
-                          self.adapter)
+                          self.adapter, len(self.arch.pattern))
 
     def init_state(self, key):
         """Parameters and AdamW state on the device, in one jitted call."""

@@ -22,8 +22,10 @@ The step is built again here as the harness builds it (``_step``), since
 the harness keeps no handle on the step it timed; a change to how the
 harness builds its step shows as time that finds no instruction.
 
-A scope is the first top-level scope name on the ``op_name`` path (and
-under ``ffn`` the MoE part, as ``ffn/router``); the phase is
+A scope is the first top-level scope name on the ``op_name`` path, and
+the child scope right beneath it where the program declares that child
+for that parent (``CHILDREN``: ``scopes.CHILDREN``, or else the MoE
+parts under ``ffn``, as ``ffn/router``); the phase is
 ``recompute`` where the path holds the checkpoint's
 ``rematted_computation``, ``backward`` where it holds a ``transpose(``,
 ``forward`` where it holds a ``jvp(``, and None outside differentiation
@@ -117,6 +119,12 @@ def op_names(hlo_text: str) -> dict[str, str]:
     return dict(_INSTR.findall(hlo_text))
 
 
+#: top-level scope → the child scopes kept beneath it: the program's
+#: ``scopes.CHILDREN``, or where it has none, the MoE parts under ``ffn``
+CHILDREN = {} if scopes is None else getattr(
+    scopes, "CHILDREN", {scopes.FFN: scopes.MOE_PARTS})
+
+
 def classify(op_name: str) -> tuple[str | None, str | None]:
     """(scope, phase) of one ``op_name``; scope None outside every named
     scope."""
@@ -125,8 +133,8 @@ def classify(op_name: str) -> tuple[str | None, str | None]:
     for i, name in enumerate(parts):
         if name in scopes.TOP_LEVEL:
             scope = name
-            if (name == scopes.FFN and i + 1 < len(parts)
-                    and parts[i + 1] in scopes.MOE_PARTS):
+            if (i + 1 < len(parts)
+                    and parts[i + 1] in CHILDREN.get(name, ())):
                 scope = f"{name}/{parts[i + 1]}"
             break
     if scopes.REMAT in parts:

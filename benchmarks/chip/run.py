@@ -70,6 +70,7 @@ def main(argv=None, root: Path = ROOT) -> int:
 
     bench = spec.Benchmark(root)
     cell = bench.cell(args.workload)
+    print(f"setup_mark imports {process_age():.2f}", file=sys.stderr)
     devices = require_chips(cell["chips"])
     configure_compile_cache()
     result, checks = harness.run_cell(

@@ -134,3 +134,22 @@ def test_calibration_reads_program_control_and_fault(root, tmp_path):
     assert [r["kind"] for r in rows] == ["program", "control", "half_batch"]
     assert all(set(check.NUMBERS) <= set(r) for r in rows)
     assert [r["correct"] for r in rows] == [True, False, False]
+
+
+@pytest.mark.parametrize("rows, seq, want", [(2, 64, (1, 64)),
+                                             (1, 64, (1, 32))],
+                         ids=["rows", "one_row"])
+def test_half_batch_keeps_half(rows, seq, want):
+    """The half-batch fault keeps the first half of the rows, or of a
+    lone row's tokens."""
+    from benchmarks.chip.traffic import TrafficSource
+
+    mix = {**tiny.SMOKE_MIX, "sequences_per_step": rows,
+           "sequence_length": seq}
+    source = TrafficSource(mix, 200, SEED)
+    half = calibrate.HalfBatch(source).batch(3)
+    full = source.batch(3)
+    for k in ("tokens", "labels"):
+        assert half[k].shape == want
+        assert (half[k] == full[k][:want[0], :want[1]]).all()
+

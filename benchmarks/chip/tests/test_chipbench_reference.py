@@ -60,10 +60,11 @@ def test_loss_and_grads_match(case):
     cfg, ref, adapter, params = case
     arch = model.arch_config(cfg, ref, adapter)
     b = batch(3)
+    p_prog = model.to_program(params, adapter, len(arch.pattern))
     with jax.default_matmul_precision("highest"):
         lp, gp = jax.value_and_grad(lambda p: decoder.loss_fn(
             p, arch, {k: jnp.asarray(v) for k, v in b.items()},
-            compute_dtype=jnp.float32))(model.to_program(params, adapter))
+            compute_dtype=jnp.float32))(p_prog)
         lr, gr = jax.value_and_grad(lambda p: ref.loss(
             p, jnp.asarray(b["tokens"]), jnp.asarray(b["labels"]), cfg,
             common.f32_matmul))(params)
@@ -89,7 +90,7 @@ def test_one_adamw_update_matches(case):
         step = jax.jit(make_train_step(
             arch, lr=opt["lr"], weight_decay=opt["weight_decay"],
             clip=opt["clip_norm"], compute_dtype=jnp.float32, microbatch=1))
-        p_prog = model.to_program(params, adapter)
+        p_prog = model.to_program(params, adapter, len(arch.pattern))
         new_prog, _, _ = step(p_prog, adamw_init(p_prog), b)
         grads = [jax.grad(lambda p: ref.loss(
             p, jnp.asarray(b["tokens"][i:i + 1]),

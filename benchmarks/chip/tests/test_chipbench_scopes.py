@@ -13,8 +13,12 @@ from __future__ import annotations
 
 import contextlib
 import gzip
+import hashlib
+import importlib
+import json
 import re
 import shutil
+from collections import Counter
 from pathlib import Path
 
 import jax
@@ -142,6 +146,60 @@ def test_costly_instructions_resolve_to_scopes(step_text):
 ])
 def test_classify(op_name, want):
     assert opscope.classify(op_name) == want
+
+
+def test_declared_children_are_kept(monkeypatch):
+    """A child scope that the program declares under a top-level scope
+    (``scopes.CHILDREN``) reads as ``<scope>/<child>``; the declaration
+    takes the place of the MoE parts under ``ffn``."""
+    assert opscope.CHILDREN == {scopes.FFN: scopes.MOE_PARTS}
+    monkeypatch.setattr(scopes, "CHILDREN",
+                        {scopes.ATTENTION: ("latent",)}, raising=False)
+    try:
+        importlib.reload(opscope)
+        base = "jit(train_step)/while/body/closed_call/jvp()/"
+        got = [opscope.classify(base + p) for p in (
+            "attention/latent/dot_general", "attention/other/mul",
+            "ffn/router/top_k")]
+    finally:
+        monkeypatch.undo()
+        importlib.reload(opscope)
+    assert got == [("attention/latent", "forward"), ("attention", "forward"),
+                   ("ffn", "forward")]
+    assert opscope.CHILDREN == {scopes.FFN: scopes.MOE_PARTS}
+
+
+#: the scoped probe's classification as it stood before child scopes could
+#: be declared: instructions by (scope, phase), and a digest of the whole
+#: map
+PROBE_SCOPES = {
+    ("attention", "backward"): 325, ("attention", "forward"): 292,
+    ("attention", "recompute"): 437, ("attention", None): 50,
+    ("cast", "backward"): 10, ("cast", "forward"): 145,
+    ("cast", "recompute"): 4, ("embed", "backward"): 11,
+    ("embed", "forward"): 68, ("ffn", "backward"): 30,
+    ("ffn", "forward"): 30, ("ffn", "recompute"): 46,
+    ("ffn/combine", "backward"): 255, ("ffn/combine", "forward"): 13,
+    ("ffn/combine", "recompute"): 6, ("ffn/dispatch", "backward"): 15,
+    ("ffn/dispatch", "forward"): 172, ("ffn/dispatch", "recompute"): 136,
+    ("ffn/dispatch", None): 15, ("ffn/experts", "backward"): 76,
+    ("ffn/experts", "forward"): 39, ("ffn/experts", "recompute"): 79,
+    ("ffn/router", "backward"): 137, ("ffn/router", "forward"): 256,
+    ("ffn/router", "recompute"): 264, ("ffn/router", None): 4,
+    ("grad_accum", None): 170, ("head", "backward"): 164,
+    ("head", "forward"): 173, ("head", "recompute"): 60,
+    ("optimizer", None): 1006, (None, "backward"): 76,
+    (None, "forward"): 45, (None, None): 500}
+PROBE_DIGEST = ("29b7cabeaf6cb10de8d1272ded3ae48650a633275dc967d0d64db183"
+                "6c774fc3")
+
+
+def test_scoped_probe_classifies_as_before(scoped_probe):
+    m = scoped_probe.op_scopes
+    assert Counter(m.values()) == PROBE_SCOPES
+    items = sorted((k, str(v)) for k, v in m.items())
+    assert hashlib.sha256(
+        json.dumps(items).encode()).hexdigest() == PROBE_DIGEST
 
 
 def test_scopes_leave_the_compiled_step_unchanged(bench, step_text,
